@@ -50,6 +50,7 @@ import numpy as np
 from repro import engine
 from repro.core import BatchSpec, EngineHooks, QSched, lower, run_plan
 from repro.kernels.qr_tile import ops
+from repro.obs import trace as _trace
 
 T_GEQRF, T_LARFT, T_TSQRF, T_SSRFT = range(4)
 TASK_NAMES = {T_GEQRF: "DGEQRF", T_LARFT: "DLARFT",
@@ -376,13 +377,19 @@ class _TileState:
 def run_qr(a: jnp.ndarray, tile: int = 32, mode: str = "sequential",
            backend: str = "pallas", nr_queues: int = 1):
     """Compute the R factor of ``a`` with the QuickSched task graph on any
-    registered execution backend.  Returns (R, sched)."""
-    tiles, mt, nt = _split_tiles(a, tile)
-    sched, _ = make_qr_graph(mt, nt, nr_queues=nr_queues)
-    state = _TileState(tiles, backend)
-    run_plan(sched, state.batch_registry(), mode,
-             nr_workers=max(nr_queues, 1), engine=state.engine_hooks())
-    r = _assemble_r(state.tiles, mt, nt, tile, a.dtype)
+    registered execution backend.  Returns (R, sched).  The host phases
+    are spanned (``qr.run`` holding ``qr.split_tiles``, ``qr.build_graph``,
+    the backend's own spans and ``qr.assemble_r``)."""
+    with _trace.span("qr.run", n=a.shape[0], tile=tile, mode=mode):
+        with _trace.span("qr.split_tiles"):
+            tiles, mt, nt = _split_tiles(a, tile)
+        with _trace.span("qr.build_graph"):
+            sched, _ = make_qr_graph(mt, nt, nr_queues=nr_queues)
+        state = _TileState(tiles, backend)
+        run_plan(sched, state.batch_registry(), mode,
+                 nr_workers=max(nr_queues, 1), engine=state.engine_hooks())
+        with _trace.span("qr.assemble_r"):
+            r = _assemble_r(state.tiles, mt, nt, tile, a.dtype)
     return r, sched
 
 

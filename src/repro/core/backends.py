@@ -35,6 +35,8 @@ from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, Mapping, Optional, Sequence,
                     Tuple)
 
+from repro.obs import trace as _trace
+
 from .executors import SequentialExecutor, ThreadedExecutor
 from .graph import FLAG_VIRTUAL, QSched
 from .plan import BatchSpec, ExecutionPlan, lower
@@ -166,7 +168,12 @@ class RoundsBackend(Backend):
 class EngineBackend(Backend):
     """Device-resident execution (DESIGN.md §Engine): the plan lowers to
     descriptor task tables through the registry's ``encode`` hooks and the
-    whole plan runs as one jitted dispatch of the family megakernel."""
+    whole plan runs as one jitted dispatch of the family megakernel.
+
+    The host work around that dispatch is spanned: ``engine.buffers``
+    (the family's statics and state buffers), ``engine.execute`` (the
+    dispatch alone — the device runs on after it returns; its time is in
+    the profiler's device trace) and ``engine.writeback``."""
 
     name = "engine"
     needs_plan = True
@@ -193,10 +200,16 @@ class EngineBackend(Backend):
         tables = lower_tables(plan, sched, registry,
                               arg_width=engine.arg_width,
                               row_access=engine.row_access)
-        out = execute_plan(tables, engine.round_fn, engine.statics(),
-                           engine.buffers(), fuse_rounds=engine.fuse_rounds,
-                           donate=engine.donate)
-        engine.writeback(out)
+        with _trace.span("engine.buffers"):
+            statics, buffers = engine.statics(), engine.buffers()
+        with _trace.span("engine.execute", items=tables.nr_items,
+                         rounds=tables.nr_rounds, phases=tables.nr_phases,
+                         fused=engine.fuse_rounds):
+            out = execute_plan(tables, engine.round_fn, statics, buffers,
+                               fuse_rounds=engine.fuse_rounds,
+                               donate=engine.donate)
+        with _trace.span("engine.writeback"):
+            engine.writeback(out)
 
 
 _BACKENDS: Dict[str, Backend] = {}

@@ -231,7 +231,7 @@ def _init_state(in_refs, out_refs) -> None:
 
 
 def _grid_walk(kernel, desc, phase_bounds, block_items, statics, buffers,
-               interpret: bool):
+               interpret: bool, name: str):
     """Walk ``desc`` over a flat grid of ragged item blocks, one
     ``pallas_call`` per SMEM-sized launch (``_launches``; one launch for
     any table of modest size): block bounds and descriptor rows are
@@ -241,7 +241,9 @@ def _grid_walk(kernel, desc, phase_bounds, block_items, statics, buffers,
     observe earlier writes).  Blocks are phase-major: programs of one
     phase touch pairwise-disjoint state rows (the write-coloring
     invariant) and may execute in any order or concurrently; phase order
-    itself is what serializes the conflicting pairs."""
+    itself is what serializes the conflicting pairs.  Every launch carries
+    the family kernel's ``name`` (``qr_megakernel``, ...), which the
+    device trace and the lowered program show."""
     statics = tuple(statics)
     buffers = tuple(buffers)
     width = desc.shape[1]
@@ -249,11 +251,12 @@ def _grid_walk(kernel, desc, phase_bounds, block_items, statics, buffers,
     for r0, r1, blocks in _launches(phase_bounds, block_items, width):
         buffers = _launch(kern, desc[r0:r1].reshape(-1),
                           jnp.asarray(blocks, jnp.int32).reshape(-1),
-                          statics, buffers, interpret)
+                          statics, buffers, interpret, name)
     return buffers
 
 
-def _launch(kernel, desc, bounds, statics, buffers, interpret: bool):
+def _launch(kernel, desc, bounds, statics, buffers, interpret: bool,
+            name: str):
     """One grid-walk ``pallas_call`` over flat ``desc``/``bounds``."""
 
     def full(a):
@@ -274,6 +277,7 @@ def _launch(kernel, desc, bounds, statics, buffers, interpret: bool):
         input_output_aliases={2 + len(statics) + i: i
                               for i in range(len(buffers))},
         interpret=interpret,
+        name=name,
     )(bounds, desc, *statics, *buffers)
 
 
@@ -343,12 +347,12 @@ def qr_round_fn(interpret: Optional[bool] = None,
     per (interpret, block_items) so the runner's jit cache is shared."""
     interp = _default_interpret(interpret)
 
-    def round_fn(desc, phase_bounds, statics, buffers):
+    def qr_round(desc, phase_bounds, statics, buffers):
         del statics
         return _grid_walk(_qr_kernel, desc, phase_bounds, block_items, (),
-                          buffers, interp)
+                          buffers, interp, "qr_megakernel")
 
-    return round_fn
+    return qr_round
 
 
 # ---------------------------------------------------------------------------
@@ -453,11 +457,11 @@ def bh_round_fn(eps: float, interpret: Optional[bool] = None,
     interp = _default_interpret(interpret)
     kern = functools.partial(_bh_kernel, eps=float(eps))
 
-    def round_fn(desc, phase_bounds, statics, buffers):
+    def bh_round(desc, phase_bounds, statics, buffers):
         return _grid_walk(kern, desc, phase_bounds, block_items, statics,
-                          buffers, interp)
+                          buffers, interp, "bh_megakernel")
 
-    return round_fn
+    return bh_round
 
 
 # ---------------------------------------------------------------------------
@@ -544,8 +548,8 @@ def pipe_round_fn(inv_m: float, interpret: Optional[bool] = None,
     interp = _default_interpret(interpret)
     kern = functools.partial(_pipe_kernel, inv_m=float(inv_m))
 
-    def round_fn(desc, phase_bounds, statics, buffers):
+    def pipe_round(desc, phase_bounds, statics, buffers):
         return _grid_walk(kern, desc, phase_bounds, block_items, statics,
-                          buffers, interp)
+                          buffers, interp, "pipe_megakernel")
 
-    return round_fn
+    return pipe_round

@@ -84,6 +84,10 @@ def _segment_runner(round_fn: RoundFn, segments: Tuple[Segment, ...],
             buffers = round_fn(desc[o0:o1], bounds, statics, buffers)
         return buffers
 
+    # the program takes the round function's name (``jit_qr_round``,
+    # ``jit_decode_round``, ...), stable across plans and visible in the
+    # device trace
+    run.__name__ = run.__qualname__ = getattr(round_fn, "__name__", "run")
     return jax.jit(run, donate_argnums=(2,) if donate else ())
 
 
@@ -109,20 +113,9 @@ def execute_plan(tables: TaskTable, round_fn: RoundFn,
     reg.counter("engine.plans_executed").inc()
     reg.counter("engine.launch_segments").inc(len(segments))
     reg.counter("engine.items_walked").inc(tables.nr_items)
-    tr = _trace.get_tracer()
-    if not tr.enabled:
-        return run(jnp.asarray(tables.desc), statics, buffers)
-    # launch-segment span: tracing forces a device sync so the span covers
-    # execution, not just the async dispatch — acceptable observer cost,
-    # paid only when a tracer is installed
-    t0 = _trace.now()
-    out = run(jnp.asarray(tables.desc), statics, buffers)
-    jax.block_until_ready(out)
-    tr.event_span("engine.execute", t0, _trace.now(), lane="engine",
-                  items=tables.nr_items, rounds=tables.nr_rounds,
-                  phases=tables.nr_phases, segments=len(segments),
-                  fused=fuse_rounds)
-    return out
+    # asynchronous in every tracing state: a traced run executes the same
+    # program as an untraced one, and device time comes from the profiler
+    return run(jnp.asarray(tables.desc), statics, buffers)
 
 
 @functools.lru_cache(maxsize=None)

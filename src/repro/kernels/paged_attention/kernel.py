@@ -218,6 +218,7 @@ def paged_gqa_call(q, k_new, v_new, k_pool, v_pool, page_rows, pos, *,
         #         [5]=k_pool [6]=v_pool;  pools alias outputs 1/2
         input_output_aliases={5: 1, 6: 2},
         interpret=_default_interpret(interpret),
+        name="paged_decode",
     )(page_rows.astype(jnp.int32), pos.astype(jnp.int32),
       qg, k_new, v_new, k_pool, v_pool)
     return o.reshape(q.shape), k_pool, v_pool
@@ -250,5 +251,6 @@ def paged_mla_call(q_eff, q_rope, c_new, r_new, c_pool, r_pool, page_rows,
         #         [5]=r_new [6]=c_pool [7]=r_pool; pools alias outputs 1/2
         input_output_aliases={6: 1, 7: 2},
         interpret=_default_interpret(interpret),
+        name="paged_decode_mla",
     )(page_rows.astype(jnp.int32), pos.astype(jnp.int32),
       q_eff, q_rope, c_new, r_new, c_pool, r_pool)

@@ -24,6 +24,18 @@ Every record carries a ``process`` label; the Chrome exporter
 is how simulator-*predicted* timelines overlay *measured* ones in a
 single Perfetto view.
 
+**One clock with the device trace.**  An enabled :class:`Tracer` mirrors
+every nested ``span()`` into JAX's profiler as a
+``jax.profiler.TraceAnnotation`` of the same name (the name only; args
+stay in the record here), so any profiler capture — a TensorBoard or
+Perfetto view of a ``jax.profiler`` trace — shows the program's spans
+as host events on the device trace's own clock, beside the device ops
+they wait on or launch.  Spans with explicit timestamps (``event_span``)
+are recorded after the fact and cannot be mirrored.  While a tracer is
+installed through :func:`enable`, a ``gc.callbacks`` hook also records
+each garbage collection as a ``gc.collect`` span (generation, objects
+collected), mirrored the same way.
+
 The process-global default tracer is a :class:`NullTracer` — a guaranteed
 near-zero-overhead no-op (``span()`` returns one shared singleton context
 manager, ``task``/``counter``/``event_span`` return immediately, and
@@ -35,6 +47,7 @@ scheduler hot path is gated ≤ 3% in ``benchmarks/sched_overhead.py``.
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from dataclasses import dataclass, field
@@ -86,7 +99,8 @@ class _Span:
     mutated inside the ``with`` block to attach results computed during
     the span (round counts, cache hits, ...)."""
 
-    __slots__ = ("_tracer", "name", "args", "t0", "_lane", "_depth")
+    __slots__ = ("_tracer", "name", "args", "t0", "_lane", "_depth",
+                 "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self._tracer = tracer
@@ -99,11 +113,15 @@ class _Span:
         stack.append(self)
         self._depth = len(stack)
         self._lane = threading.current_thread().name
+        # the profiler's annotation encloses the record's interval
+        self._mirror = tr._annotation(self.name)
+        self._mirror.__enter__()
         self.t0 = now()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = now()
+        self._mirror.__exit__(None, None, None)
         tr = self._tracer
         tr._stack().pop()
         with tr._lock:
@@ -134,17 +152,23 @@ _NULL_SPAN = _NullSpan()
 class Tracer:
     """Thread-safe trace record store.  All three record kinds append
     under one lock; reads (the exporter, tests) take snapshots via the
-    plain list attributes after the traced region has quiesced."""
+    plain list attributes after the traced region has quiesced.  Nested
+    spans are mirrored into JAX's profiler (module docstring)."""
 
     enabled = True
 
     def __init__(self, process: str = DEFAULT_PROCESS):
+        # imported here, not at module load: repro.obs itself imports
+        # nothing, and only a recording tracer needs the profiler
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.process = process
         self.spans: List[SpanRecord] = []
         self.tasks: List[TaskRecord] = []
         self.counters: List[CounterSample] = []
         self._lock = threading.Lock()
         self._local = threading.local()
+        self._gc_open: Optional[tuple] = None
         self.t_start = now()
 
     def _stack(self) -> List[_Span]:
@@ -188,6 +212,28 @@ class Tracer:
             self.counters.append(CounterSample(
                 name, now() if t is None else float(t), float(value),
                 process or self.process))
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook: one ``gc.collect`` span per collection,
+        nested under whatever span the collecting thread has open.  The
+        record is appended without the lock — a collection can start
+        while this thread holds it, and ``list.append`` is atomic."""
+        if phase == "start":
+            mirror = self._annotation("gc.collect")
+            mirror.__enter__()
+            self._gc_open = (now(), mirror)
+            return
+        if self._gc_open is None:      # hooked mid-collection
+            return
+        t0, mirror = self._gc_open
+        t1 = now()
+        mirror.__exit__(None, None, None)
+        self._gc_open = None
+        self.spans.append(SpanRecord(
+            "gc.collect", t0, t1, threading.current_thread().name,
+            len(self._stack()) + 1, self.process,
+            {"generation": info["generation"],
+             "collected": info["collected"]}))
 
     # -- introspection ------------------------------------------------------
     @property
@@ -246,15 +292,23 @@ def get_tracer() -> Union[Tracer, NullTracer]:
 
 
 def set_tracer(tracer: Union[Tracer, NullTracer]) -> Union[Tracer, NullTracer]:
+    """Install ``tracer`` as the global default; the one it replaces stops
+    recording garbage collections."""
     global _default
+    hook = getattr(_default, "_on_gc", None)
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
     _default = tracer
     return tracer
 
 
 def enable(process: str = DEFAULT_PROCESS) -> Tracer:
     """Install (and return) a fresh recording tracer as the global
-    default."""
-    return set_tracer(Tracer(process))
+    default, recording garbage collections as ``gc.collect`` spans until
+    it is replaced."""
+    tracer = set_tracer(Tracer(process))
+    gc.callbacks.append(tracer._on_gc)
+    return tracer
 
 
 def disable() -> None:

@@ -86,7 +86,12 @@ another conflict round.  The service's failure model:
 Every transition is metered (``serve.preemptions`` / ``serve.retries`` /
 ``serve.rejected`` / ``serve.deadline_exceeded`` / ``serve.cancelled``)
 and traced (``request.preempted`` spans, counter tracks) through the
-``repro.obs`` registry and Perfetto export.
+``repro.obs`` registry and Perfetto export.  The host path of a tick is
+spanned where the work happens — ``serve.step`` around the tick,
+``serve.admit`` with a ``serve.prefill`` per prefill group,
+``serve.decode``, and a ``serve.sync`` around each device→host read
+(counted exactly in ``serve.host_syncs``) — and an enabled tracer
+mirrors those spans onto the profiler's clock.
 
 Continuous-batched decode is token-for-token identical to the sequential
 ``serving.prefill``/``decode_step`` reference per request (conformance
@@ -516,7 +521,7 @@ class GenerateService:
                                     "generated_tokens", "pages_attended",
                                     "preemptions", "retries", "rejected",
                                     "deadline_exceeded", "cancelled",
-                                    "faults_injected")}
+                                    "faults_injected", "host_syncs")}
         self._g_pages = self.metrics.gauge("serve.pages_in_use")
         self._g_queue = self.metrics.gauge("serve.queue_depth")
         self._g_active = self.metrics.gauge("serve.active_slots")
@@ -634,6 +639,10 @@ class GenerateService:
         admit whatever fits (conflict-round prefill), then one guarded
         continuous-batched decode over every active slot.  Returns True
         while any request is queued or in flight."""
+        with _trace.span("serve.step"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         tick = self._counters["steps"].value
         before = (self._counters["admitted"].value,
                   self._counters["retired"].value)
@@ -649,17 +658,12 @@ class GenerateService:
             ps = self.pool.page_size
             pages = (sum(self._active[s].pos // ps + 1 for s in slots)
                      if self.paged else len(slots))
-            tr = _trace.get_tracer()
-            t0 = _trace.now()
-            ok_slots = self._decode_tick(slots)
-            self._counters["decode_items"].inc(len(slots))
-            self._counters["pages_attended"].inc(pages)
-            tok_h = np.asarray(self._tok)      # one sync per tick
-            pos_h = np.asarray(self._pos)
-            if tr.enabled:
-                tr.event_span("serve.decode", t0, _trace.now(),
-                              lane="engine", path=self.decode_path,
-                              batch=len(slots), pages_attended=pages)
+            with _trace.span("serve.decode", batch=len(slots), pages=pages):
+                ok_slots = self._decode_tick(slots)
+                self._counters["decode_items"].inc(len(slots))
+                self._counters["pages_attended"].inc(pages)
+                tok_h = self._read("tokens", self._tok)
+                pos_h = self._read("positions", self._pos)
             for slot in ok_slots:
                 req = self._active[slot]
                 req.generated.append(int(tok_h[slot]))
@@ -698,6 +702,14 @@ class GenerateService:
         return {"prefill_plens": sorted({p for p, _ in self._prefill_fns}),
                 "prefill_shapes": sorted(self._prefill_fns),
                 "decode_batch_sizes": sorted(self.decode_batch_sizes_seen)}
+
+    def _read(self, what: str, x) -> np.ndarray:
+        """Every device→host read of the tick path goes through here: it
+        waits for the device, so it is counted (``serve.host_syncs``) and
+        spanned (``serve.sync``, ``what`` names the read)."""
+        self._counters["host_syncs"].inc()
+        with _trace.span("serve.sync", what=what):
+            return np.asarray(x)
 
     # -- fault application (chaos harness) -----------------------------------
     def _apply_faults(self, tick: int) -> None:
@@ -767,37 +779,39 @@ class GenerateService:
             batch.append(req)
         if not batch:
             return 0
-        # lower the batch as a conflict round over canonical page
-        # resources (single round + single coloring phase proven by
-        # plan_admission), then execute the PREFILL family through the
-        # rounds backend — run_one is the jitted prefill entry point
-        try:
-            if self._admission_fault:
-                self._admission_fault = False
-                raise AdmissionConflict("injected admission failure (chaos)")
-            sched, plan = self.pool.plan_admission(
-                [r.pages for r in batch], TT_PREFILL, datas=batch,
-                nr_lanes=self.nr_lanes)
-        except AdmissionConflict:
-            # roll back: pages to the free list, slots returned, requests
-            # requeued in arrival order — retried next tick.  The pool
-            # must come out of the rollback conserving every page.
-            for req in reversed(batch):
-                self.pool.free(req.pages)
-                req.pages = []
-                self._free_slots.append(req.slot)
-                req.slot = -1
-                self._queue.appendleft(req)
-            self.pool.check_invariants()
-            self._counters["retries"].inc(len(batch))
-            return 0
-        run_plan(sched, self.registry, "rounds", plan=plan)
-        self._counters["admitted"].inc(len(batch))
-        for req in batch:
-            req.status = "active"
-            if len(req.generated) >= req.max_new_tokens:
-                self._retire(req)      # prompt-only requests never decode
-        return len(batch)
+        with _trace.span("serve.admit", batch=len(batch)):
+            # lower the batch as a conflict round over canonical page
+            # resources (single round + single coloring phase proven by
+            # plan_admission), then execute the PREFILL family through the
+            # rounds backend — run_one is the jitted prefill entry point
+            try:
+                if self._admission_fault:
+                    self._admission_fault = False
+                    raise AdmissionConflict(
+                        "injected admission failure (chaos)")
+                sched, plan = self.pool.plan_admission(
+                    [r.pages for r in batch], TT_PREFILL, datas=batch,
+                    nr_lanes=self.nr_lanes)
+            except AdmissionConflict:
+                # roll back: pages to the free list, slots returned, requests
+                # requeued in arrival order — retried next tick.  The pool
+                # must come out of the rollback conserving every page.
+                for req in reversed(batch):
+                    self.pool.free(req.pages)
+                    req.pages = []
+                    self._free_slots.append(req.slot)
+                    req.slot = -1
+                    self._queue.appendleft(req)
+                self.pool.check_invariants()
+                self._counters["retries"].inc(len(batch))
+                return 0
+            run_plan(sched, self.registry, "rounds", plan=plan)
+            self._counters["admitted"].inc(len(batch))
+            for req in batch:
+                req.status = "active"
+                if len(req.generated) >= req.max_new_tokens:
+                    self._retire(req)      # prompt-only requests never decode
+            return len(batch)
 
     def _run_prefill(self, tid: int, req: Request) -> None:
         self._prefill_group([req])
@@ -817,40 +831,41 @@ class GenerateService:
         feeds = [req.feed_tokens() for req in reqs]
         plen = int(feeds[0].size)
         nb = len(reqs)
-        fn = self._prefill_fns.get((plen, nb))
-        if fn is None:
-            fn = self._prefill_fns[(plen, nb)] = self._make_prefill_fn(
-                plen, nb)
-        np_p = self.pool.pages_needed(plen)
-        # only the first ceil(plen/ps) pages hold prompt positions; the
-        # rest of each request's pages fill one decode-scatter at a time
-        page_ids = np.zeros((nb, np_p), np.int32)
-        pt_rows = np.zeros((nb, self.max_pages), np.int32)
-        slots = np.zeros((nb,), np.int32)
-        base_key = jax.random.PRNGKey(self.sampling.seed)
-        req_keys = np.stack(
-            [np.asarray(jax.random.fold_in(base_key, req.rid))
-             for req in reqs])
-        for i, req in enumerate(reqs):
-            page_ids[i] = req.pages[:np_p]
-            pt_rows[i, :len(req.pages)] = req.pages
-            slots[i] = req.slot
-        tokens = np.stack(feeds)
-        (tok0, self.pool.leaves, self._pt, self._tok, self._pos,
-         self._keys) = fn(
-            self.params, jnp.asarray(tokens), self.pool.leaves,
-            jnp.asarray(page_ids), jnp.asarray(pt_rows),
-            jnp.asarray(slots), jnp.asarray(req_keys), self._pt,
-            self._tok, self._pos, self._keys)
-        tok0_h = np.asarray(tok0)
-        t = self._now()                # prefill yields the next token
-        for i, req in enumerate(reqs):
-            req.generated.append(int(tok0_h[i]))
-            req.pos = plen
-            if not req.t_first:
-                req.t_first = t
-            self._active[req.slot] = req
-            self._counters["generated_tokens"].inc()
+        with _trace.span("serve.prefill", plen=plen, nb=nb):
+            fn = self._prefill_fns.get((plen, nb))
+            if fn is None:
+                fn = self._prefill_fns[(plen, nb)] = self._make_prefill_fn(
+                    plen, nb)
+            np_p = self.pool.pages_needed(plen)
+            # only the first ceil(plen/ps) pages hold prompt positions; the
+            # rest of each request's pages fill one decode-scatter at a time
+            page_ids = np.zeros((nb, np_p), np.int32)
+            pt_rows = np.zeros((nb, self.max_pages), np.int32)
+            slots = np.zeros((nb,), np.int32)
+            base_key = jax.random.PRNGKey(self.sampling.seed)
+            req_keys = np.stack(
+                [self._read("keys", jax.random.fold_in(base_key, req.rid))
+                 for req in reqs])
+            for i, req in enumerate(reqs):
+                page_ids[i] = req.pages[:np_p]
+                pt_rows[i, :len(req.pages)] = req.pages
+                slots[i] = req.slot
+            tokens = np.stack(feeds)
+            (tok0, self.pool.leaves, self._pt, self._tok, self._pos,
+             self._keys) = fn(
+                self.params, jnp.asarray(tokens), self.pool.leaves,
+                jnp.asarray(page_ids), jnp.asarray(pt_rows),
+                jnp.asarray(slots), jnp.asarray(req_keys), self._pt,
+                self._tok, self._pos, self._keys)
+            tok0_h = self._read("first_token", tok0)
+            t = self._now()                # prefill yields the next token
+            for i, req in enumerate(reqs):
+                req.generated.append(int(tok0_h[i]))
+                req.pos = plen
+                if not req.t_first:
+                    req.t_first = t
+                self._active[req.slot] = req
+                self._counters["generated_tokens"].inc()
 
     def _make_prefill_fn(self, plen: int, nb: int) -> Callable:
         cfg = self.cfg
@@ -905,7 +920,7 @@ class GenerateService:
         self.decode_batch_sizes_seen.add(len(slots))
         if not self.guard:
             return slots
-        flags_h = np.asarray(self._flags)
+        flags_h = self._read("flags", self._flags)
         bad = [s for s in slots if flags_h[s] != FLAG_OK]
         if not bad:
             self._note_clean_tick()
@@ -928,7 +943,7 @@ class GenerateService:
         run_plan(rsched, self.registry, "engine",
                  plan=lower(rsched, self.nr_lanes),
                  engine=self._hooks_by_path[self._ladder[-1]])
-        flags_h = np.asarray(self._flags)
+        flags_h = self._read("flags", self._flags)
         still_bad = [s for s in bad if flags_h[s] != FLAG_OK]
         for s in still_bad:
             # restore once more so the requeued request's host state is

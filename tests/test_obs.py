@@ -376,6 +376,13 @@ class TestServiceObservability:
         lanes = {s.lane for s in tracer.spans
                  if s.name == "request"}
         assert lanes == {f"req {r.rid}" for r in reqs}
+        # engine.execute is a nested span now (the dispatch, inside the
+        # tick's serve.decode), on the serving thread's own lane
+        main = threading.current_thread().name
+        decodes = [s for s in tracer.spans if s.name == "serve.decode"]
+        for ex in (s for s in tracer.spans if s.name == "engine.execute"):
+            assert ex.lane == main and ex.depth >= 3
+            assert any(d.t0 <= ex.t0 <= ex.t1 <= d.t1 for d in decodes)
 
         path = str(tmp_path / "serve.json")
         summary = write_chrome_trace(path, registry=svc.metrics)
