@@ -113,7 +113,7 @@ def bench_qr():
     tables = engine.lower_tables(
         plan, sched, state.batch_registry(),
         arg_width=engine.QR_ARG_WIDTH, row_access=engine.qr_row_access)
-    stack0 = jnp.stack([tiles[i, j] for j in range(nt) for i in range(mt)])
+    stack0 = qr._stack_tiles(a, b)
     walks = _time_engine_walks(
         tables, engine.qr_round_fn(), (),
         lambda: (stack0 + 0.0, jnp.zeros_like(stack0)))

@@ -41,6 +41,7 @@ Execution modes (all dispatched through the core backend registry,
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -48,8 +49,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import engine
-from repro.core import BatchSpec, EngineHooks, QSched, lower, run_plan
+from repro.core import (BatchSpec, EngineHooks, QSched, get_backend, lower,
+                        run_plan)
 from repro.kernels.qr_tile import ops
+from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
 T_GEQRF, T_LARFT, T_TSQRF, T_SSRFT = range(4)
@@ -254,7 +257,33 @@ def _assemble_r(tiles, mt, nt, b, dtype):
     return jnp.concatenate(rows, axis=0)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_tiles(a: jnp.ndarray, b: int) -> jnp.ndarray:
+    """``a``'s (b, b) tiles as one (mt·nt, b, b) array, tile (i, j) at
+    index j·mt + i — the column-major order of the resource ids and of the
+    megakernel's tile arguments.  Rows and columns past the last whole
+    tile are dropped, as in ``_split_tiles``."""
+    mt, nt = a.shape[0] // b, a.shape[1] // b
+    return (a[:mt * b, :nt * b].reshape(mt, b, nt, b)
+            .transpose(2, 0, 1, 3).reshape(nt * mt, b, b))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _unstack_r(stack: jnp.ndarray, mt: int, nt: int) -> jnp.ndarray:
+    """R from the factored tile stack: the inverse of ``_stack_tiles``,
+    then one ``triu`` of the whole matrix.  Equal to ``_assemble_r``:
+    tiles above the tile diagonal lie wholly above the matrix diagonal,
+    tiles below it wholly below, and a diagonal tile's ``triu`` is its
+    part of the matrix's."""
+    b = stack.shape[-1]
+    return jnp.triu(stack.reshape(nt, mt, b, b).transpose(1, 2, 0, 3)
+                    .reshape(mt * b, nt * b))
+
+
 class _TileState:
+    """QR state of the host modes: a dict of (b, b) tiles that the task
+    bodies replace one tile at a time."""
+
     def __init__(self, tiles, backend):
         self.tiles = tiles
         self.t_diag = {}
@@ -349,23 +378,28 @@ class _TileState:
                                encode=enc_ssrft),
         }
 
-    def engine_hooks(self) -> EngineHooks:
-        """Engine-family hooks for the backend registry: stack the tile
-        dict into a (ntiles, b, b) buffer (column-major tile index,
-        matching the resource ids), run the fused QR megakernel, scatter
-        the tiles back."""
-        mt, nt = self.mt, self.nt
 
+class _TileStack(_TileState):
+    """QR state of a ``device_resident`` backend: every tile in one
+    (mt·nt, b, b) device array in ``_stack_tiles`` order, carried from the
+    split to R with no per-tile Python.  It shares the descriptor encoders
+    of ``batch_registry``; its host task bodies are never called, since
+    the engine runs every task inside the megakernel."""
+
+    def __init__(self, a: jnp.ndarray, b: int, backend):
+        self.stack = _stack_tiles(a, b)
+        self.mt, self.nt = a.shape[0] // b, a.shape[1] // b
+        self.tiles, self.backend = None, backend
+
+    def engine_hooks(self) -> EngineHooks:
+        """Engine-family hooks for the backend registry: the stack and a
+        zeroed T buffer are the fused QR megakernel's state; its output
+        stack becomes the state."""
         def buffers():
-            tiles = jnp.stack([self.tiles[i, j]
-                               for j in range(nt) for i in range(mt)])
-            return tiles, jnp.zeros_like(tiles)
+            return self.stack, jnp.zeros_like(self.stack)
 
         def writeback(out):
-            tiles, _ = out
-            for j in range(nt):
-                for i in range(mt):
-                    self.tiles[i, j] = tiles[j * mt + i]
+            self.stack = out[0]
 
         return EngineHooks(
             arg_width=engine.QR_ARG_WIDTH,
@@ -377,19 +411,30 @@ class _TileState:
 def run_qr(a: jnp.ndarray, tile: int = 32, mode: str = "sequential",
            backend: str = "pallas", nr_queues: int = 1):
     """Compute the R factor of ``a`` with the QuickSched task graph on any
-    registered execution backend.  Returns (R, sched).  The host phases
+    registered execution backend.  Returns (R, sched).  A
+    ``device_resident`` backend keeps the tiles as one device array
+    (``_TileStack``); the host modes mutate a dict of tiles
+    (``_TileState``); the counters ``qr.glue.stacked_plans`` and
+    ``qr.glue.per_tile_plans`` count the plans of each.  The host phases
     are spanned (``qr.run`` holding ``qr.split_tiles``, ``qr.build_graph``,
     the backend's own spans and ``qr.assemble_r``)."""
+    stacked = get_backend(mode).device_resident
+    _metrics.get_registry().counter(
+        "qr.glue.stacked_plans" if stacked
+        else "qr.glue.per_tile_plans").inc()
     with _trace.span("qr.run", n=a.shape[0], tile=tile, mode=mode):
         with _trace.span("qr.split_tiles"):
-            tiles, mt, nt = _split_tiles(a, tile)
+            state = (_TileStack(a, tile, backend) if stacked
+                     else _TileState(_split_tiles(a, tile)[0], backend))
+        mt, nt = state.mt, state.nt
         with _trace.span("qr.build_graph"):
             sched, _ = make_qr_graph(mt, nt, nr_queues=nr_queues)
-        state = _TileState(tiles, backend)
         run_plan(sched, state.batch_registry(), mode,
-                 nr_workers=max(nr_queues, 1), engine=state.engine_hooks())
+                 nr_workers=max(nr_queues, 1),
+                 engine=state.engine_hooks() if stacked else None)
         with _trace.span("qr.assemble_r"):
-            r = _assemble_r(state.tiles, mt, nt, tile, a.dtype)
+            r = (_unstack_r(state.stack, mt, nt) if stacked
+                 else _assemble_r(state.tiles, mt, nt, tile, a.dtype))
     return r, sched
 
 

@@ -304,8 +304,7 @@ class TestSimulatorReplay:
         tables = engine.lower_tables(
             plan, sched, state.batch_registry(),
             arg_width=engine.QR_ARG_WIDTH, row_access=engine.qr_row_access)
-        stack = jnp.stack([tiles[i, j]
-                           for j in range(nt) for i in range(mt)])
+        stack = qr._stack_tiles(a, 32)
         fn = engine.qr_round_fn()
         round_times = None
         for _ in range(3):      # elementwise best-of-3 absorbs CI jitter
@@ -352,8 +351,7 @@ class TestSimulatorReplay:
         tables = engine.lower_tables(
             plan, sched, state.batch_registry(),
             arg_width=engine.QR_ARG_WIDTH, row_access=engine.qr_row_access)
-        stack = jnp.stack([tiles[i, j]
-                           for j in range(nt) for i in range(mt)])
+        stack = qr._stack_tiles(a, 32)
         timings = engine.measure_round_times(
             tables, engine.qr_round_fn(), (),
             (stack, jnp.zeros_like(stack)), per_item=True)

@@ -324,8 +324,7 @@ class TestQREngine:
         tables = engine.lower_tables(
             plan, s, state.batch_registry(),
             arg_width=engine.QR_ARG_WIDTH, row_access=engine.qr_row_access)
-        stack = jnp.stack([tiles[i, j]
-                           for j in range(nt) for i in range(mt)])
+        stack = qr._stack_tiles(a, 32)
         tmat = jnp.zeros_like(stack)
         # donate=False: the same buffers are deliberately reused across
         # the two calls (donation would delete them on TPU/GPU)
@@ -348,8 +347,7 @@ class TestQREngine:
         tables = engine.lower_tables(
             plan, s, state.batch_registry(),
             arg_width=engine.QR_ARG_WIDTH, row_access=engine.qr_row_access)
-        stack = jnp.stack([tiles[i, j]
-                           for j in range(nt) for i in range(mt)])
+        stack = qr._stack_tiles(a, 32)
         outs = []
         for bi in (1, 64):
             fn = engine.qr_round_fn(block_items=bi)

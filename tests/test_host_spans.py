@@ -195,6 +195,31 @@ def test_qr_plan_spans_its_glue(tracer):
             assert s.depth == run.depth + 1
 
 
+@pytest.mark.parametrize("mode, counter", [
+    ("engine", "qr.glue.stacked_plans"),
+    ("sequential", "qr.glue.per_tile_plans")])
+def test_qr_plan_spans_glue_once_and_counts_its_tile_state(
+        tracer, mode, counter):
+    """An engine plan keeps the glue spans that ``glue_host_ms.plan``
+    reads, once each, and counts as a stacked plan; a host-mode plan
+    counts as a per-tile one."""
+    from repro.apps.qr import run_qr
+    from repro.obs import get_registry
+    reg = get_registry()
+    names = ("qr.glue.stacked_plans", "qr.glue.per_tile_plans")
+    before = {n: reg.counter(n).value for n in names}
+    a = jax.random.normal(jax.random.PRNGKey(2), (64, 64), jnp.float32)
+    run_qr(a, tile=32, mode=mode)
+    spans = [s.name for s in tracer.spans]
+    glue = ("qr.split_tiles", "qr.build_graph", "qr.assemble_r")
+    if mode == "engine":
+        glue += ("engine.buffers", "engine.writeback")
+    for name in glue:
+        assert spans.count(name) == 1, name
+    assert {n: reg.counter(n).value - before[n] for n in names} == {
+        n: int(n == counter) for n in names}
+
+
 def _bump(desc, phase_bounds, statics, buffers):
     return (buffers[0] + desc.shape[0],)
 
