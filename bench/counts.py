@@ -5,6 +5,8 @@ happens to execute: a causal prefill needs half of the S x S score
 matrix, and a decode step reads the pages it attends, not the whole pool.
 A multiply-add is 2 operations.
 
+The model counts are those of a dense GQA + SwiGLU decoder, which an
+architecture's file (``archs/<model_type>.py``) takes where they fit.
 Model sizes use the configuration file's keys (Hugging Face names):
 ``hidden_size`` d, ``intermediate_size`` f, ``num_hidden_layers`` L,
 ``num_attention_heads`` H, ``num_key_value_heads`` K, ``head_dim`` h,

@@ -2,6 +2,13 @@
 ``repro.serve.GenerateService`` as ``launch/serve.py --continuous``
 builds it, under one traffic mix.
 
+Nothing here knows a model: what depends on the architecture (the
+program's configuration, how each weight is drawn, the operations and
+bytes counted, the decode kernel's name in the trace) is in
+``archs/<model_type>.py``, and the plain reference in
+``reference/<model_type>.py``, both found by the configuration file's
+``model_type``.
+
 A run: make the weights on the device from the seed (one jitted call),
 build the service, warm every shape the mix can use (each prefill
 ``(prompt length, batch)`` entry and each decode batch size), then drive
@@ -14,8 +21,8 @@ window the load goes on (uncounted) until the window's requests have
 finished, for at most :data:`DRAIN_S`.
 
 Then the device's peak memory is read, the service is freed, and the
-plain reference (``reference/qwen3.py``) reads a sample of the finished
-requests, drawn from the seed with the longest among them.
+plain reference reads a sample of the finished requests, drawn from the
+seed with the longest among them.
 """
 
 from __future__ import annotations
@@ -28,84 +35,45 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-import counts
 import devtrace
 import gen
 import harness
-from reference import qwen3 as ref
 
 DRAIN_S = 60.0              # wait past the window for its last requests
 
 
-# --- configuration and weights ----------------------------------------------
-
-_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-         "num_attention_heads": "n_heads",
-         "num_key_value_heads": "n_kv_heads",
-         "intermediate_size": "d_ff", "vocab_size": "vocab",
-         "head_dim": "head_dim", "rope_theta": "rope_theta",
-         "rms_norm_eps": "norm_eps",
-         "tie_word_embeddings": "tie_embeddings"}
-
-
-def model_config(c: Dict[str, Any]):
-    """The program's ModelConfig for the configuration file: the repo's
-    own entry with every size taken from the file."""
-    from repro.configs import get_config
-    over = {v: c[k] for k, v in _KEYS.items()}
-    over["dtype"] = c["torch_dtype"]
-    return dataclasses.replace(get_config(c["program_config"]), **over)
-
+# --- weights --------------------------------------------------------------
 
 def make_weights(c: Dict[str, Any], seed_word: int):
-    """Random weights in bf16 (norm scales f32), in the layout the
-    program takes, made on the device in one jitted call: matrices
-    N(0, 1/fan_in), embeddings N(0, 1/d), norm scales 1 + N(0, 0.1^2).
-    A tied head is the embedding itself and has no leaf of its own."""
+    """Random weights in the layout and dtypes the program takes, made
+    on the device in one jitted call, each drawn as the architecture's
+    ``init_leaf`` says."""
     import jax
     return jax.jit(weight_maker(c))(jax.random.PRNGKey(seed_word))
 
 
 def weight_maker(c: Dict[str, Any]) -> Callable:
-    """key -> the weights of :func:`make_weights`."""
+    """key -> the weights of :func:`make_weights`: the program's own
+    parameter tree (``lm.init_params`` traced for shapes only), one
+    standard normal draw per leaf from ``key`` split in the tree's
+    order, shaped by ``init_leaf`` and cast to the leaf's dtype."""
     import jax
     import jax.numpy as jnp
+    from repro.models import lm
 
-    d, f, hd = c["hidden_size"], c["intermediate_size"], c["head_dim"]
-    nh, nk, L, V = (c["num_attention_heads"], c["num_key_value_heads"],
-                    c["num_hidden_layers"], c["vocab_size"])
-    bf = jnp.dtype(c["torch_dtype"])
-    shapes = {
-        "embed": ({"tok": (V, d)} if c["tie_word_embeddings"]
-                  else {"tok": (V, d), "head": (d, V)}),
-        "final_norm": {"scale": (d,)},
-        "layers": {
-            "attn_norm": {"scale": (L, d)},
-            "attn": {"wq": (L, d, nh * hd), "wk": (L, d, nk * hd),
-                     "wv": (L, d, nk * hd), "wo": (L, nh * hd, d),
-                     "q_norm": {"scale": (L, hd)},
-                     "k_norm": {"scale": (L, hd)}},
-            "mlp_norm": {"scale": (L, d)},
-            "mlp": {"w_gate": (L, d, f), "w_up": (L, d, f),
-                    "w_down": (L, f, d)}}}
-    leaves, tree = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(
-        x, tuple))
-    paths = [jax.tree_util.keystr(p) for p, _ in
-             jax.tree_util.tree_flatten_with_path(
-                 shapes, is_leaf=lambda x: isinstance(x, tuple))[0]]
+    arch = harness.arch(c["model_type"])
+    cfg = arch.program_config(c)
+    tree = jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                          jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
 
     def build(key):
         keys = jax.random.split(key, len(leaves))
-        out = []
-        for k, shape, path in zip(keys, leaves, paths):
-            z = jax.random.normal(k, shape, jnp.float32)
-            if "scale" in path:
-                out.append(1.0 + 0.1 * z)
-            elif "tok" in path:
-                out.append((z * d ** -0.5).astype(bf))
-            else:
-                out.append((z * shape[-2] ** -0.5).astype(bf))
-        return jax.tree.unflatten(tree, out)
+        out = [arch.init_leaf(jax.tree_util.keystr(path),
+                              jax.random.normal(k, leaf.shape, jnp.float32),
+                              c).astype(leaf.dtype)
+               for k, (path, leaf) in zip(keys, leaves)]
+        return jax.tree.unflatten(treedef, out)
 
     return build
 
@@ -136,14 +104,16 @@ class ServeCell:
         import jax
         from repro.serve import GenerateService, SamplingParams
         self.c, self.mix = c, mix
+        self.ref = harness.reference(c["model_type"])
         words = harness.seed_words(seed)
         self.weights = make_weights(c, words(0))
         jax.block_until_ready(self.weights)
         s = c["service"]
         self.svc = GenerateService(
-            self.weights, model_config(c), max_batch=s["max_batch"],
-            max_seq=s["max_seq"], page_size=s["page_size"],
-            decode_path=s["decode_path"], guard=s["guard"],
+            self.weights, harness.arch(c["model_type"]).program_config(c),
+            max_batch=s["max_batch"], max_seq=s["max_seq"],
+            page_size=s["page_size"], decode_path=s["decode_path"],
+            guard=s["guard"],
             sampling=SamplingParams(temperature=0.0, seed=words(1)))
 
     def set_seed(self, seed: int) -> None:
@@ -322,8 +292,9 @@ class ServeCell:
         seqs = [(w.reqs[i].prompt,
                  np.asarray(w.handles[i].generated, np.int32))
                 for i in pick]
-        return ref.served_gaps(self.weights, self.c, seqs, control=control,
-                               pad_p=ref_pad_p(self.mix))
+        return self.ref.served_gaps(self.weights, self.c, seqs,
+                                    control=control,
+                                    pad_p=ref_pad_p(self.mix))
 
     def free_service(self) -> None:
         """Drop the service and its pool, keeping the weights."""
@@ -339,6 +310,7 @@ def ref_pad_p(mix: Dict[str, Any]) -> int:
 
 def measure(w: Window, c: Dict[str, Any]) -> Dict[str, Any]:
     """End-to-end numbers and the per-layer context of one window."""
+    arch = harness.arch(c["model_type"])
     ttft, itl = [], []
     prefill_fl = decode_fl = 0.0
     in_window = 0
@@ -357,12 +329,12 @@ def measure(w: Window, c: Dict[str, Any]) -> Dict[str, Any]:
                 break
             in_window += 1
             if k == 0:
-                fl = counts.prefill_flops(c, plen)
+                fl = arch.prefill_flops(c, plen)
                 prefill_fl += fl
                 if t_lo <= t <= t_hi:
                     trace_prefill_fl += fl
             else:
-                decode_fl += counts.decode_flops(c, plen + k - 1)
+                decode_fl += arch.decode_flops(c, plen + k - 1)
     # the window waits for its requests (up to DRAIN_S): one that never
     # finished failed
     not_done = [i for i in counted if w.handles[i].status != "done"]

@@ -1,10 +1,11 @@
 """What every cell shares: files found by name, the device guard, the
 compile cache, the compile watch, quantiles and the result line.
 
-Nothing here knows a configuration, a traffic mix or a metric by name:
-``BENCHMARK.json`` names them, and each lives in a file of its own
-(``configs/<config>.json``, ``traffic/<mix>.json``,
-``metrics/<metric>.py``, ``drivers/<kind>.py``).
+Nothing here knows a configuration, a traffic mix, a metric or a model
+architecture by name: ``BENCHMARK.json`` and the configuration files name
+them, and each lives in a file of its own (``configs/<config>.json``,
+``traffic/<mix>.json``, ``metrics/<metric>.py``, ``drivers/<kind>.py``,
+``archs/<model_type>.py``, ``reference/<model_type>.py``).
 """
 
 from __future__ import annotations
@@ -62,6 +63,23 @@ def load_module(path: pathlib.Path, name: str):
 
 def driver(kind: str):
     return load_module(BENCH / "drivers" / f"{kind}.py", f"driver_{kind}")
+
+
+def arch(model_type: str):
+    """What the serving driver and its readers need of one architecture
+    (a configuration file's Hugging Face ``model_type``):
+    ``program_config(c)``, ``init_leaf(path, z, c)``,
+    ``prefill_flops(c, plen)``, ``decode_flops(c, ctx)``,
+    ``page_bytes(c, page_size)``, ``DECODE_KERNELS`` and
+    ``small(c, control=False)``."""
+    return load_module(BENCH / "archs" / f"{model_type}.py",
+                       f"arch_{model_type}")
+
+
+def reference(model_type: str):
+    """The plain reference of one architecture, with its control."""
+    return load_module(BENCH / "reference" / f"{model_type}.py",
+                       f"reference_{model_type}")
 
 
 def cell_metrics(spec: Dict[str, Any], cell: str, section: str

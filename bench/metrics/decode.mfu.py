@@ -1,7 +1,7 @@
 """Decode's share of the chip's bf16 peak over the whole window (host
 clock): operations of every decoded token stamped inside the window
-(``counts.decode_flops`` at its cache position) over window seconds
-times peak."""
+(the architecture's ``decode_flops`` at its cache position,
+``archs/<model_type>.py``) over window seconds times peak."""
 
 import harness
 

@@ -1,6 +1,7 @@
 """Prefill's share of the chip's bf16 peak over the whole window (host
 clock): operations of every prefill that yielded a first token inside the
-window (``counts.prefill_flops``) over window seconds times peak."""
+window (the architecture's ``prefill_flops``, ``archs/<model_type>.py``)
+over window seconds times peak."""
 
 import harness
 

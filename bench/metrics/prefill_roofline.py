@@ -1,6 +1,7 @@
 """Share of the bf16 peak reached by the prefill programs while they run:
 operations of the prefills that yielded a first token inside the traced
-stretch over the device time of the ``prefill_entry`` modules there.
+stretch (the architecture's ``prefill_flops``) over the device time of
+the ``prefill_entry`` modules there.
 Compute-bound at these lengths (operations per weight byte = prompt
 tokens in the batch)."""
 

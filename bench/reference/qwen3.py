@@ -9,8 +9,9 @@ RMSNorm and an output head.  Sizes, ``rope_theta``, ``rms_norm_eps`` and
 whether the head is tied come from the configuration file as it is run.
 It imports nothing of the program.
 
-Weights are the benchmark's own (``drivers/serve.py make_weights``), in
-the nested layout the program takes: ``embed.tok`` (V, d), ``embed.head``
+Weights are the benchmark's own (``drivers/serve.py make_weights``, each
+leaf drawn as ``archs/qwen3.py init_leaf`` says), in the nested layout
+the program takes: ``embed.tok`` (V, d), ``embed.head``
 (d, V) where the head is untied (a tied head reads ``embed.tok``
 transposed), ``final_norm.scale``, and under ``layers`` every tensor stacked on
 a leading layer axis: ``attn_norm.scale``, ``attn.{wq,wk,wv,wo}``,

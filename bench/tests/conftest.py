@@ -25,12 +25,24 @@ SPEC = harness.load_spec()
 DEVICE = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 
 
-def small_serve_config(**over):
-    """The qwen3-1.7b file with small widths, its limits kept."""
-    c = harness.load_config(SPEC, "qwen3-1.7b")
-    c.update(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
-             num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-             vocab_size=512)
+# the serving configurations, by name: every ``configs`` entry whose
+# file is of kind ``serve``
+SERVE_CONFIGS = [e["name"] for e in SPEC["configs"]
+                 if harness.load_config(SPEC, e["name"])["kind"] == "serve"]
+
+
+def serve_cell(config):
+    """The first ``workloads`` entry of a configuration."""
+    return next(w["name"] for w in SPEC["workloads"]
+                if w["config"] == config)
+
+
+def small_serve_config(name, control=False, **over):
+    """A serving configuration's file at its architecture's CPU-test
+    widths (``small`` of ``archs/<model_type>.py``) and a small service,
+    its limits kept."""
+    c = harness.load_config(SPEC, name)
+    c = harness.arch(c["model_type"]).small(c, control=control)
     c["service"] = dict(c["service"], max_batch=2, max_seq=128,
                         decode_path="bounded")
     c.update(over)

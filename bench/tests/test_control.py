@@ -6,17 +6,21 @@ e4m3 products for the served bfloat16 model, three-pass bfloat16
 products for the float32-HIGHEST QR.  At a small size it must read above
 the cell's limit while the program, on the same inputs, reads below it.
 ``bench/calibrate.py limits`` makes the same readings on the chip at the
-cells' own sizes."""
+cells' own sizes.  The serving test runs for every serving configuration
+of ``BENCHMARK.json``, at its architecture's ``small(c, control=True)``
+widths."""
 
 import numpy as np
+import pytest
 
 import harness
-from conftest import small_chat_mix, small_qr_config, small_serve_config
+from conftest import (SERVE_CONFIGS, small_chat_mix, small_qr_config,
+                      small_serve_config)
 
 
-def test_serving_control_fails_the_limit():
-    c = small_serve_config(hidden_size=256, intermediate_size=512,
-                           num_hidden_layers=4, vocab_size=4096)
+@pytest.mark.parametrize("config", SERVE_CONFIGS)
+def test_serving_control_fails_the_limit(config):
+    c = small_serve_config(config, control=True)
     mix = small_chat_mix()
     sc = harness.driver("serve").ServeCell(c, mix, 5)
     sc.warm()

@@ -4,16 +4,19 @@ Each test drives a whole run of a cell at a small size (everything but
 the look for a chip), with one of the faults a cell of its kind can have
 planted in the program, and reads ``correct`` and the compared numbers
 against the limits of the cell's own configuration file.  The sound run
-of each kind comes out correct."""
+of each kind comes out correct.  The serving tests run for every serving
+configuration of ``BENCHMARK.json``."""
 
 import pytest
 
-from conftest import (run_cell, small_chat_mix, small_qr_config,
-                      small_serve_config)
+import harness
+from conftest import (SERVE_CONFIGS, SPEC, run_cell, serve_cell,
+                      small_chat_mix, small_qr_config, small_serve_config)
 
 
-def _serve():
-    return run_cell("qwen3-1.7b.chat", small_serve_config(), small_chat_mix())
+def _serve(config):
+    return run_cell(serve_cell(config), small_serve_config(config),
+                    small_chat_mix())
 
 
 def _qr():
@@ -21,13 +24,15 @@ def _qr():
                     {"kind": "plans", "check_plans": 3}, seconds=1.0)
 
 
-def test_sound_serving_run_is_correct():
-    result, checks = _serve()
+@pytest.mark.parametrize("config", SERVE_CONFIGS)
+def test_sound_serving_run_is_correct(config):
+    result, checks = _serve(config)
     assert result["correct"], checks
     assert result["attempted"] > 0 and result["failed"] == 0
 
 
-def test_served_token_altered_is_caught(monkeypatch):
+@pytest.mark.parametrize("config", SERVE_CONFIGS)
+def test_served_token_altered_is_caught(monkeypatch, config):
     """A token altered where it is produced: greedy sampling returns the
     id after the argmax."""
     from repro.models import serving
@@ -38,13 +43,14 @@ def test_served_token_altered_is_caught(monkeypatch):
         return (tok + 1) % logits.shape[-1], keys
 
     monkeypatch.setattr(serving, "sample_tokens", altered)
-    result, checks = _serve()
+    result, checks = _serve(config)
     assert not result["correct"]
     assert checks["served_gap_max"]["value"] > checks["served_gap_max"][
         "limit"]
 
 
-def test_decode_step_returning_its_state_is_caught(monkeypatch):
+@pytest.mark.parametrize("config", SERVE_CONFIGS)
+def test_decode_step_returning_its_state_is_caught(monkeypatch, config):
     """A decode step that returns the cache it was given: the new
     token's keys and values are never written."""
     from repro.models import serving
@@ -55,7 +61,7 @@ def test_decode_step_returning_its_state_is_caught(monkeypatch):
         return logits, cache
 
     monkeypatch.setattr(serving, "decode_step", stale)
-    result, checks = _serve()
+    result, checks = _serve(config)
     assert not result["correct"]
     assert checks["served_gap_max"]["value"] > checks["served_gap_max"][
         "limit"]
@@ -99,7 +105,8 @@ def test_qr_answer_altered_is_caught(monkeypatch):
         "limit"]
 
 
-@pytest.mark.parametrize("cell", ["qwen3-1.7b.chat", "qr-2048.paper"])
+@pytest.mark.parametrize(
+    "cell", [serve_cell(c) for c in SERVE_CONFIGS] + ["qr-2048.paper"])
 def test_traced_run_reports_per_layer_metrics(cell):
     if cell == "qr-2048.paper":
         result, _ = run_cell(cell, small_qr_config(),
@@ -107,8 +114,9 @@ def test_traced_run_reports_per_layer_metrics(cell):
                              seconds=1.0, trace=1)
         assert "sched_host_ms.plan" in result["metrics"]
     else:
-        result, _ = run_cell(cell, small_serve_config(), small_chat_mix(),
-                             trace=1)
+        config = harness.find_cell(SPEC, cell)["config"]
+        result, _ = run_cell(cell, small_serve_config(config),
+                             small_chat_mix(), trace=1)
         assert "sched_host_ms.tick" in result["metrics"]
         assert "ttft_p50_ms" not in result["metrics"]
     assert {"busy_s", "window_s"} <= set(result["device"])
